@@ -110,6 +110,9 @@ def main(argv=None) -> None:
                         help="tiny graphs + small microbenches (CI path)")
     args = parser.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from benchmarks import (ablation_hooking, fig1_runtime, fig2_depth,
                             table1_steps, table2_stats, table3_bcc,
                             table4_dynamic, table5_dynamic_bcc,

@@ -37,9 +37,12 @@ This engine is the single home for those loops (DESIGN.md §3):
                             with the same amortization.
 
 ``interpret=None`` everywhere dispatches from ``jax.default_backend()``:
-compiled Mosaic on TPU, the Pallas interpreter elsewhere. The kernel path
-pads to the (8, 128) tile once, *outside* the convergence loop, and runs
-the whole loop on the padded 2-D table.
+Mosaic on TPU, the Pallas interpreter elsewhere. The kernel path pads to
+the (8, 128) tile once, *outside* the convergence loop, and runs the
+whole loop on the padded 2-D table. Of the kernels behind
+``use_kernel=True`` only ``segment_table`` compiles for a TPU; the
+pointer_jump and list_rank kernels raise the compiler's refusal there
+(DESIGN.md §3).
 """
 from __future__ import annotations
 
@@ -55,7 +58,7 @@ DEFAULT_JUMPS = 5
 
 
 def default_interpret() -> bool:
-    """Pallas interpret-mode dispatch: compiled on TPU, interpreter elsewhere.
+    """Pallas interpret-mode dispatch: Mosaic on TPU, interpreter elsewhere.
 
     Single policy shared with every kernel ops wrapper
     (``repro.kernels.auto_interpret``)."""

@@ -28,7 +28,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.compress import compress_full
@@ -102,11 +101,11 @@ def distributed_cc_spanning_forest(mesh: Mesh, axis: str = "data"):
             cond, body, (p0, forest0, jnp.int32(0), jnp.bool_(True)))
         return p, forest, rounds - 1
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step_fn, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),
         out_specs=(P(), P(axis), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     @partial(jax.jit, static_argnames=("n_nodes",))

@@ -78,10 +78,9 @@ class Graph:
         hi = np.maximum(edges[:, 0], edges[:, 1])
         keep = lo != hi
         lo, hi = lo[keep], hi[keep]
-        key = lo * n_nodes + hi
-        _, idx = np.unique(key, return_index=True)
-        u = lo[idx].astype(np.int32)
-        v = hi[idx].astype(np.int32)
+        key = np.unique(lo * n_nodes + hi)
+        u = (key // n_nodes).astype(np.int32)
+        v = (key % n_nodes).astype(np.int32)
         return Graph.from_undirected(n_nodes, jnp.asarray(u), jnp.asarray(v))
 
 

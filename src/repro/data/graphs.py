@@ -56,14 +56,16 @@ def rmat(scale: int, edge_factor: int = 8, seed: int = 0,
     m = n * edge_factor
     src = np.zeros(m, np.int64)
     dst = np.zeros(m, np.int64)
+    ab, lower, upper = a + b, a / (a + b), c / (1 - a - b)
     for _ in range(scale):
         r1 = rng.random(m)
         r2 = rng.random(m)
-        src_bit = (r1 > a + b).astype(np.int64)
-        dst_bit = (((r1 <= a + b) & (r2 > a / (a + b))) |
-                   ((r1 > a + b) & (r2 > c / (1 - a - b)))).astype(np.int64)
-        src = (src << 1) | src_bit
-        dst = (dst << 1) | dst_bit
+        src_bit = r1 > ab
+        dst_bit = np.where(src_bit, r2 > upper, r2 > lower)
+        src <<= 1
+        src |= src_bit
+        dst <<= 1
+        dst |= dst_bit
     edges = np.concatenate([np.stack([src, dst], 1), _implant_tree(n, rng)])
     return Graph.from_numpy_undirected(n, edges)
 
@@ -103,6 +105,22 @@ SUITE = {
 def build_suite(names=None) -> dict[str, Graph]:
     names = names or list(SUITE)
     return {k: SUITE[k][0](**SUITE[k][1]) for k in names}
+
+
+#: Regime of each ``resolve_graph`` pattern kind (SUITE names carry theirs).
+PATTERN_REGIMES = {
+    "chain": "extreme-diameter",
+    "grid": "high-diameter road-like",
+    "rmat": "power-law low-diameter",
+    "er": "random control",
+}
+
+
+def graph_regime(name: str) -> str:
+    """The structural regime of a ``resolve_graph`` name."""
+    if name in SUITE:
+        return SUITE[name][2]
+    return PATTERN_REGIMES.get(name.partition("_")[0], "unknown")
 
 
 def resolve_graph(name: str, seed: int = 0) -> Graph:

@@ -71,11 +71,13 @@ def _edges_of(graph: Graph) -> np.ndarray:
                     axis=1).astype(np.int64)
 
 
-def _pad(pairs: list[tuple[int, int]], width: int, n: int):
+def _pad(pairs, width: int, n: int):
+    """Pad a [k, 2] pair array (or list of pairs) to ``width`` with ``n``."""
+    pairs = np.asarray(pairs, np.int64).reshape(-1, 2)[:width]
     u = np.full(width, n, np.int32)
     v = np.full(width, n, np.int32)
-    for i, (a, b) in enumerate(pairs[:width]):
-        u[i], v[i] = a, b
+    u[:pairs.shape[0]] = pairs[:, 0]
+    v[:pairs.shape[0]] = pairs[:, 1]
     return u, v
 
 
@@ -146,24 +148,22 @@ def churn(graph: Graph, *, batch: int = 64, n_batches: int = 16,
     edges = _edges_of(graph)
     m = edges.shape[0]
     perm = rng.permutation(m)
-    live = [tuple(edges[i]) for i in perm[:m // 2]]
-    dead = [tuple(edges[i]) for i in perm[m // 2:]]
-    init_u = np.asarray([e[0] for e in live], np.int32)
-    init_v = np.asarray([e[1] for e in live], np.int32)
+    # live / dead hold edge ids in list order; a step pops the chosen
+    # positions (highest first) and appends the swapped edges at the end.
+    live = perm[:m // 2]
+    dead = perm[m // 2:]
+    init_u = edges[live, 0].astype(np.int32)
+    init_v = edges[live, 1].astype(np.int32)
     k = max(1, batch // 2)
     batches = []
     for _ in range(n_batches):
         kk = min(k, len(live), len(dead))
-        dels, ins = [], []
-        for i in sorted(rng.choice(len(live), size=kk, replace=False),
-                        reverse=True):
-            dels.append(live.pop(i))
-        for i in sorted(rng.choice(len(dead), size=kk, replace=False),
-                        reverse=True):
-            ins.append(dead.pop(i))
-        live += ins
-        dead += dels
-        batches.append(_mk_batch(ins, dels, batch, n))
+        d_pos = np.sort(rng.choice(len(live), size=kk, replace=False))[::-1]
+        i_pos = np.sort(rng.choice(len(dead), size=kk, replace=False))[::-1]
+        dels, ins = live[d_pos], dead[i_pos]
+        live = np.concatenate([np.delete(live, d_pos), ins])
+        dead = np.concatenate([np.delete(dead, i_pos), dels])
+        batches.append(_mk_batch(edges[ins], edges[dels], batch, n))
     return EdgeStream(name="churn", n_nodes=n,
                       init_u=init_u, init_v=init_v,
                       batches=tuple(batches))

@@ -218,9 +218,14 @@ def edge_slots(state: DynamicForest, del_u: jnp.ndarray,
     hi = jnp.concatenate([jnp.where(state.pool_valid, phi, n), qhi])
     is_query = jnp.concatenate([jnp.zeros((cap,), jnp.bool_),
                                 jnp.ones((d,), jnp.bool_)])
-    idx = jnp.arange(total, dtype=jnp.int32)
 
-    order = jnp.lexsort((idx, is_query, hi, lo)).astype(jnp.int32)
+    # Lexicographic (lo, hi, is_query, slot) order as two stable argsorts
+    # (least significant first): the same permutation as a 4-key
+    # lexsort, at a third of its compile time for a v5e (4.2M slots:
+    # 140 s vs 2 x 22 s on the compiling host).
+    assert n < 2**30, "hi * 2 + is_query must fit int32"
+    by_hi = jnp.argsort(hi * 2 + is_query, stable=True)
+    order = by_hi[jnp.argsort(lo[by_hi], stable=True)].astype(jnp.int32)
     slo, shi, squery = lo[order], hi[order], is_query[order]
 
     # Segment machinery over sorted (lo, hi) groups.

@@ -122,7 +122,10 @@ class QuerySession:
                 "live forest — run refresh_bcc before building a "
                 "QuerySession")
         if tn is None:
-            tn = tour_numbering(state.parent, use_kernel=self.use_kernel)
+            # The same compiled program as the refresh path's (which
+            # always asks for the syncs): one compile, not two.
+            tn, _ = tour_numbering(state.parent, use_kernel=self.use_kernel,
+                                   return_syncs=True)
         self.tables = q.build_tables(tn, n_jumps=self.n_jumps)
         self.tn = tn
         self.bcc = bcc

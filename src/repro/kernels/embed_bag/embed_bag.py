@@ -14,6 +14,8 @@ and double-buffers row DMAs — same body, different memory_space (documented
 adaptation, cf. DESIGN.md §2).
 
 sum/mean reduction; per-sample weights optional (weights == None → ones).
+The TPU compiler refuses the row ``jnp.take`` ("Shape mismatch in input,
+indices and output"): interpret mode only (DESIGN.md §3).
 """
 from __future__ import annotations
 

@@ -5,10 +5,11 @@ table and emit the frontier-expansion mask
 
     active(e) = (dist[src] == level) & (dist[dst] == INF)
 
-The deterministic parent scatter-min stays in XLA. On TPU this kernel fuses
-the two gathers and both compares into one VMEM pass over the edge list —
-one launch per BFS level, which is exactly the Θ(diam) launch count the
-paper attributes BFS's poor high-diameter behavior to.
+The deterministic parent scatter-min stays in XLA. The kernel fuses the two
+gathers and both compares into one pass over the edge list — one launch
+per BFS level, the Θ(diam) launch count the paper attributes BFS's poor
+high-diameter behavior to. The TPU compiler refuses its ``jnp.take`` at
+every size, as for pointer_jump: interpret mode only (DESIGN.md §3).
 """
 from __future__ import annotations
 

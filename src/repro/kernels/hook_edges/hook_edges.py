@@ -12,8 +12,9 @@ Outputs per half-edge:
        or ``n`` (dropped) for non-cross edges;
   val: proposed new parent (lo resp. hi).
 
-Edge arrays are viewed as (E/128, 128) tiles; the rep table is VMEM-resident
-(same budget note as pointer_jump).
+Edge arrays are viewed as (E/128, 128) tiles; the rep table is VMEM-resident.
+The TPU compiler refuses its ``jnp.take`` at every size, as for
+pointer_jump: interpret mode only (DESIGN.md §3).
 """
 from __future__ import annotations
 

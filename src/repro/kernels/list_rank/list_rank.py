@@ -8,7 +8,9 @@ the Euler-tour analogue of the multi-jump trick. Semantics per step:
     succ = has ? succ[succ] : -1
 
 Layout matches pointer_jump: (R, 128) int32 tiles, full tables VMEM-resident,
-8-sublane-aligned blocks. Sentinel -1 terminates lists; padded slots carry
+8-sublane-aligned blocks — and, like pointer_jump, the whole-table
+``jnp.take`` is refused by the TPU compiler at every size, so this kernel
+runs in interpret mode only (DESIGN.md §3). Sentinel -1 terminates lists; padded slots carry
 succ = -1, dist = 0 and are inert.
 """
 from __future__ import annotations

@@ -10,13 +10,10 @@ through HBM.
 Layout: vertex ids are viewed as a (n/128, 128) int32 matrix — rows of 128
 lanes, the native VREG lane width — and blocks are (BLOCK_ROWS, 128) tiles,
 8-sublane aligned. The gather is a flat ``jnp.take`` on the VMEM-resident
-table (dynamic-gather on TPU; exact in interpret mode).
-
-VMEM budget: the table tile is n × 4 bytes; n ≤ ~3.5M keeps table + block
-under the 16 MB VMEM ceiling. Larger graphs run the same kernel over a
-vertex partition with the table in ANY/HBM memory space (documented
-trade-off; the multi-chip path in ``core.distributed`` shards edges
-instead).
+table: exact in interpret mode, but the TPU compiler refuses it at every
+size ("Only 2D gather is supported" — Mosaic gathers only within a
+vreg), so neither kernel here runs on a chip (``tests/test_tpu_compile.py``
+holds them as strict xfails; DESIGN.md §3).
 """
 from __future__ import annotations
 

@@ -13,10 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import auto_interpret as _auto_interpret
-from repro.kernels.segment_table.segment_table import (BLOCK_ROWS, LANES,
+from repro.kernels.segment_table.segment_table import (LANES, block_rows,
                                                        segment_table_pallas)
-
-_TILE = BLOCK_ROWS * LANES
 
 
 @partial(jax.jit, static_argnames=("levels", "op", "interpret"))
@@ -32,7 +30,10 @@ def segment_table(values: jnp.ndarray, *, levels: int, op: str,
         interpret = _auto_interpret()
     with jax.named_scope("segment_table"):
         n = values.shape[0]
-        n_pad = -n % _TILE
+        rows = -(-n // (8 * LANES)) * 8            # 8-sublane aligned
+        b = block_rows(rows)
+        rows = -(-rows // b) * b                   # whole blocks
+        n_pad = rows * LANES - n
         if jnp.issubdtype(values.dtype, jnp.integer):
             info = jnp.iinfo(values.dtype)
         else:

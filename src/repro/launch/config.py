@@ -120,7 +120,9 @@ class ServeConfig:
         always had, so existing invocations keep working)."""
         g = ap.add_argument_group("workload")
         g.add_argument("--graph", default=StreamConfig.graph,
-                       help="data.graphs.SUITE name")
+                       help="data.graphs.SUITE name, or a chain_<n>/"
+                            "grid_<side>/rmat_<scale>/er_<n> pattern "
+                            "(data.graphs.resolve_graph)")
         g.add_argument("--stream", default=StreamConfig.stream,
                        choices=STREAM_NAMES)
         g.add_argument("--batch", type=int, default=StreamConfig.batch)

@@ -42,9 +42,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
     t_build = time.time() - t0
 
     t0 = time.time()
-    # jax.set_mesh is post-0.4.x; the Mesh context manager is the
-    # equivalent pjit-era spelling for establishing the ambient mesh.
-    with getattr(jax, "set_mesh", lambda m: m)(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step_fn).lower(state_abs, inputs_abs)
     t_lower = time.time() - t0
 

@@ -10,15 +10,8 @@ import jax
 
 
 def auto_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=Auto`` where the jax version has it, ``{}`` otherwise.
-
-    ``jax.sharding.AxisType`` landed after 0.4.x; Auto is the pre-AxisType
-    default behavior, so omitting the kwarg on older jax is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """``jax.make_mesh`` kwargs that make every axis ``AxisType.Auto``."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

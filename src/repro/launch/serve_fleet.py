@@ -69,13 +69,16 @@ def main(argv=None) -> None:
     except ValueError as e:
         ap.error(str(e))
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     if fcfg.buckets:
         _main_bucketed(cfg, fcfg)
         return
 
     import jax
 
-    from repro.data.graphs import SUITE
+    from repro.data.graphs import graph_regime, resolve_graph
     from repro.data.streams import STREAMS
     from repro.dynamic.fleet import (FleetDispatcher, FleetManager,
                                      FleetQuerySession, apply_batches,
@@ -84,8 +87,11 @@ def main(argv=None) -> None:
                                      refresh_tours)
     from repro.dynamic.replay import stream_capacity
 
-    factory, kwargs, regime = SUITE[cfg.stream.graph]
-    g = factory(**kwargs)
+    try:
+        g = resolve_graph(cfg.stream.graph, seed=cfg.stream.seed)
+    except ValueError as e:
+        ap.error(str(e))
+    regime = graph_regime(cfg.stream.graph)
     n = g.n_nodes
 
     # Per-tenant streams: same workload shape, decorrelated seeds. The

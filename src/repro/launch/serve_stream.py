@@ -25,9 +25,9 @@ The sustained rate counts *applied* updates only: insertions dropped by
 pool overflow and deletions that matched no live edge are excluded (and
 reported on a separate dropped-events line when nonzero) — the rate
 reflects work done, not traffic offered. ``--validate`` cross-checks the
-final forest against a from-scratch build (``core.validate`` oracles)
-with a vectorized canonical-relabel partition comparison over *all*
-vertices.
+final forest with the ``core.validate`` oracles and its partition against
+a from-scratch GConn ``connected_components``, by a vectorized
+canonical-relabel comparison over *all* vertices.
 
 Since the query-layer PR the loop also serves *reads* (DESIGN.md §12):
 ``--read-ratio r`` interleaves query batches (LCA / connectivity /
@@ -202,15 +202,21 @@ def main(argv=None) -> None:
     except ValueError as e:
         ap.error(str(e))
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     import jax
 
-    from repro.data.graphs import SUITE
+    from repro.data.graphs import graph_regime, resolve_graph
     from repro.data.streams import STREAMS
     from repro.dynamic.chaos import INJECTORS
     from repro.launch.resilient import ResilientStreamLoop
 
-    factory, kwargs, regime = SUITE[cfg.stream.graph]
-    g = factory(**kwargs)
+    try:
+        g = resolve_graph(cfg.stream.graph, seed=cfg.stream.seed)
+    except ValueError as e:
+        ap.error(str(e))
+    regime = graph_regime(cfg.stream.graph)
     n = g.n_nodes
     stream = STREAMS[cfg.stream.stream](g, **cfg.stream_kwargs())
     batches = stream.batches[:cfg.stream.steps]
@@ -354,17 +360,17 @@ def main(argv=None) -> None:
         print(f"metrics -> {cfg.obs.metrics_out}")
 
     if cfg.validate:
-        from repro.core.compress import roots_of
-        from repro.core.rst import rooted_spanning_tree
+        from repro.core.connectivity import connected_components
         from repro.core.validate import validate_rst
         from repro.dynamic import live_graph
 
         lg = live_graph(state)
         root = int(np.asarray(state.rep)[0])
         v = validate_rst(lg, np.asarray(state.parent), root, connected=False)
-        scratch = rooted_spanning_tree(lg, root, method="gconn_euler")
+        # The from-scratch partition is GConn's; a tree on top of it would
+        # add a pool-wide Euler tour program and nothing to the comparison.
         rep_d = np.asarray(state.rep)
-        rep_s = np.asarray(roots_of(scratch.parent))
+        rep_s = np.asarray(connected_components(lg)[0])
         same = bool(np.array_equal(canonical_partition(rep_d),
                                    canonical_partition(rep_s)))
         print(f"validate: forest {v}, partition==from-scratch: {same} "

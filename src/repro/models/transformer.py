@@ -316,7 +316,6 @@ def moe_block_shard_map(x, router_w, w_gate, w_up, w_down, cfg: LMConfig,
     residual slice + 3 weight gathers — versus the fallback's multi-GB
     all-reduces per scatter/gather pair.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     da = tuple(cfg.act_batch_axes)
@@ -384,13 +383,13 @@ def moe_block_shard_map(x, router_w, w_gate, w_up, w_down, cfg: LMConfig,
         return jax.lax.psum(out, model_ax)
 
     x_spec = P(da or None, seq_ax, None)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None),
                   P(model_ax, "data", None), P(model_ax, "data", None),
                   P(model_ax, None, "data")),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, w_gate, w_up, w_down)
 
 

@@ -1,6 +1,8 @@
 """Multi-device tests (subprocess: needs 8 fake host devices, which must be
 set before jax initializes — the main test process keeps 1 device)."""
 import json
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -61,9 +63,7 @@ step_fn, state_abs, _ = build_cell(spec, "smoke", mesh2, smoke=True)
 params = tfm.init_params(cfg, jax.random.key(0))
 state = {"params": params, "opt": adamw_init(params)}
 _, batch = next(synthetic_batches(spec, shape, cfg))
-# jax.set_mesh is post-0.4.x; the Mesh context manager is the equivalent
-# pjit-era spelling for establishing the ambient mesh.
-with getattr(jax, "set_mesh", lambda m: m)(mesh2):
+with jax.set_mesh(mesh2):
     new_state, metrics = jax.jit(step_fn)(state, batch)
 out["sharded_train"] = dict(loss=float(metrics["loss"]),
                             finite=bool(jnp.isfinite(metrics["loss"])))
@@ -73,10 +73,16 @@ print("RESULT:" + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def multi_device_results():
+    # The child inherits the parent's environment but is held to the CPU:
+    # a child that reached for the TPU library while this worker (or a
+    # compile test in it) holds it would fail or hang.
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   filter(None, [src, os.environ.get("PYTHONPATH")])))
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        timeout=600, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                          "HOME": "/root"})
+        timeout=600, env=env)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
     assert line, proc.stdout

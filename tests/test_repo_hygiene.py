@@ -11,7 +11,7 @@ _ROOT = pathlib.Path(__file__).resolve().parent.parent
 # Patterns .gitignore must carry — dropping one silently reopens the
 # door to committed bytecode/caches.
 _REQUIRED_IGNORES = ("__pycache__/", "*.pyc", ".pytest_cache/",
-                     "artifacts/")
+                     "artifacts/", ".jax_cache/")
 
 
 def _tracked_files():

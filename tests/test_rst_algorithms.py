@@ -64,6 +64,66 @@ def test_bfs_distances_match_reference():
     assert np.array_equal(got, ref)
 
 
+def _broom(spokes):
+    """Vertex 0 with ``spokes`` paths of lengths 1-5 hanging off it."""
+    edges, nxt = [], 1
+    for i in range(spokes):
+        prev = 0
+        for _ in range(1 + i % 5):
+            edges.append((prev, nxt))
+            prev, nxt = nxt, nxt + 1
+    return Graph.from_numpy_undirected(nxt, np.array(edges))
+
+
+def _bfs_min_src_reference(g, root):
+    """Level-synchronous BFS where each new vertex takes its smallest
+    frontier neighbour as parent (the conflict rule ``bfs_rst`` keeps)."""
+    src, dst = np.asarray(g.src), np.asarray(g.dst)
+    parent = np.full(g.n_nodes, -1, np.int64)
+    dist = np.full(g.n_nodes, -1, np.int64)
+    parent[root], dist[root], level = root, 0, 0
+    while True:
+        on = (dist[src] == level) & (dist[dst] < 0)
+        if not on.any():
+            return parent, dist, level
+        for v in np.unique(dst[on]):
+            parent[v] = src[on & (dst == v)].min()
+        dist[np.unique(dst[on])] = level + 1
+        level += 1
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["dense_and_sparse", "dense_kernel"])
+@pytest.mark.parametrize("name,g,root", [
+    ("grid", G.grid2d(9), 40),
+    ("rmat", G.rmat(7, edge_factor=4, seed=2), 0),
+    # Vertex 6 has no edges at all and is the highest id: its out-edge
+    # list is empty and sits past the last half-edge.
+    ("isolated_tail", Graph.from_numpy_undirected(
+        7, np.array([(0, 3), (3, 1), (1, 0), (4, 5), (2, 3), (2, 0)])), 3),
+    # 300 spokes of lengths 1-5 around vertex 0: the first front (300)
+    # overflows frontier_cap(901) = 256, so level 1 runs dense and the
+    # levels after it sparse again.
+    ("broom", _broom(300), 0),
+    # Repeated half-edges: a sparse level must list each vertex once.
+    ("multigraph", Graph.from_undirected(
+        6, np.array([0, 0, 1, 1, 2, 3, 3]), np.array([1, 1, 2, 2, 3, 4, 5])),
+     0),
+    # No half-edges: the root alone, zero levels, before any loop.
+    ("edgeless", Graph.from_numpy_undirected(4, np.zeros((0, 2))), 2),
+])
+def test_bfs_parent_is_min_frontier_neighbour(name, g, root, use_kernel):
+    """Sparse and dense levels, and the kernel's all-dense levels, keep
+    the minimum-src rule."""
+    parent, dist, levels = bfs_rst(g, root, use_kernel=use_kernel)
+    ref_parent, ref_dist, ref_levels = _bfs_min_src_reference(g, root)
+    assert np.array_equal(np.asarray(parent), ref_parent), name
+    got = np.asarray(dist).astype(np.int64)
+    got[got == np.iinfo(np.int32).max] = -1
+    assert np.array_equal(got, ref_dist), name
+    assert int(levels) == ref_levels, name
+
+
 def test_connectivity_matches_union_find():
     rng = np.random.default_rng(5)
     edges = np.stack([rng.integers(0, 200, 150),
