@@ -1,0 +1,12 @@
+"""euler_root_ms: device time of the jitted ``euler_tour_root`` program per build.
+
+Summed over the ``XLA Modules`` events named ``jit_euler_tour_root`` in the
+profiler trace of the traced builds, divided by the builds traced. A
+trace with modules but none of that name is reported on standard error
+(``bench.tracing.program_ms``): the program was renamed or fused away.
+"""
+from bench import tracing
+
+
+def read(run):
+    return tracing.program_ms(run, "euler_tour_root")
