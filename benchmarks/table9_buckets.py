@@ -138,20 +138,27 @@ def _run_bucketed(groups):
                      seed=init_state(s, capacity=grp["schema"].capacity))
             for unit in s.batches[:grp["units"]]:
                 bf.offer(tid, unit)
-    with obs.SyncLedger() as led:
+    with obs.Tracer() as tracer:
         bf.run()
         bf.finalize()
     for b in bf.buckets.values():
         jax.block_until_ready(b.manager.fleet.parent)
     # The ledger is the reporting path; the per-bucket counters are the
     # regression oracle — both count the same while_loop carries, and
-    # the bucket labels must attribute every record.
+    # each ``fleet_apply`` count must sit in its bucket's tick span.
+    led = tracer.ledger
     apply_sum = sum(b.sync_apply for b in bf.buckets.values())
     assert led.total("fleet_apply") == apply_sum, \
         (led.total("fleet_apply"), apply_sum)
-    assert led.by_bucket("fleet_apply") == {
-        b.name: b.sync_apply for b in bf.buckets.values()
-        if b.sync_apply}, led.by_bucket("fleet_apply")
+    bucket_of = {s["id"]: s["args"]["bucket"]
+                 for s in tracer.spans("bucket_tick")}
+    per_bucket: dict[str, int] = {}
+    for c in tracer.counts("fleet_apply"):
+        name = bucket_of[c["parent"]]
+        per_bucket[name] = per_bucket.get(name, 0) + c["value"]
+    want = {b.name: b.sync_apply for b in bf.buckets.values()
+            if b.sync_apply}
+    assert per_bucket == want, (per_bucket, want)
     return bf
 
 

@@ -37,7 +37,7 @@ ch = json.load(open(f"{d}/trace.jsonl.chrome.json"))
 assert isinstance(ch["traceEvents"], list) and ch["traceEvents"], \
     "no traceEvents"
 for ev in ch["traceEvents"]:
-    assert ev["ph"] in ("X", "i"), f"bad phase {ev['ph']!r}"
+    assert ev["ph"] in ("X", "C", "i"), f"bad phase {ev['ph']!r}"
     assert isinstance(ev["name"], str) and isinstance(ev["ts"], int)
     if ev["ph"] == "X":
         assert isinstance(ev["dur"], int)
@@ -49,7 +49,7 @@ print(f"obs_smoke: chrome export ok "
 # Round trip: chrome export reconstructs the native records.
 from repro.obs import chrome_to_records, read_jsonl
 native = [r for r in read_jsonl(f"{d}/trace.jsonl")
-          if r["type"] in ("span", "event")]
+          if r["type"] in ("span", "count", "event")]
 assert chrome_to_records(ch) == native, "chrome round-trip mismatch"
 
 # Metrics registry: per-tenant labels landed.

@@ -11,6 +11,7 @@ from typing import Literal
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.bfs import bfs_rst as _bfs_rst
 from repro.core.connectivity import connected_components as _connected_components
 from repro.core.euler import euler_tour_root as _euler_tour_root
@@ -48,6 +49,7 @@ def gconn_euler_rst(graph: Graph, root, *, use_kernel: bool = False):
 
     ``use_kernel`` reaches both jump phases: GConn's shortcutting (multi-jump
     pointer_jump kernel) and the Euler list ranking (list_rank kernel).
+    GConn's round count goes to the ambient ``obs`` ledger as ``gconn``.
     """
     n = graph.n_nodes
     rep, forest_mask, rounds = _connected_components(graph,
@@ -67,6 +69,10 @@ def gconn_euler_rst(graph: Graph, root, *, use_kernel: bool = False):
 
     parent = _euler_tour_root(n, fu, fv, valid, comp_root,
                               use_kernel=use_kernel)
+    # Recorded once Euler rooting is queued, so a traced pull waits on
+    # GConn alone; nothing is recorded while tracing a jitted caller.
+    if not isinstance(rounds, jax.core.Tracer):
+        obs.record("gconn", lambda: int(rounds))
     return parent, rep, rounds
 
 

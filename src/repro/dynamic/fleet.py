@@ -198,7 +198,7 @@ def _apply_batches(fleet: ForestFleet, ins_u: jnp.ndarray,
 def apply_batches(fleet: ForestFleet, ins_u: jnp.ndarray,
                   ins_v: jnp.ndarray, del_u: jnp.ndarray,
                   del_v: jnp.ndarray, *, n_jumps: int = DEFAULT_JUMPS,
-                  use_kernel: bool = False, bucket: str | None = None):
+                  use_kernel: bool = False):
     """Apply one ``(T, B)`` event block: one vmapped §9 batch per tenant.
 
     Args:
@@ -215,13 +215,11 @@ def apply_batches(fleet: ForestFleet, ins_u: jnp.ndarray,
 
     Host wrapper over the jitted block apply: reports the tick's sync
     bill (``fleet_sync_cost``) to the ambient ``obs`` ledger under the
-    ``fleet_apply`` phase (labeled with the sub-fleet ``bucket`` when
-    one is ticking, §15).
+    ``fleet_apply`` phase.
     """
     fleet, stats = _apply_batches(fleet, ins_u, ins_v, del_u, del_v,
                                   n_jumps=n_jumps, use_kernel=use_kernel)
-    obs.record("fleet_apply", lambda: fleet_sync_cost(stats),
-               bucket=bucket)
+    obs.record("fleet_apply", lambda: fleet_sync_cost(stats))
     return fleet, stats
 
 
@@ -235,8 +233,7 @@ def fleet_sync_cost(stats) -> int:
 # -- vmapped cache refreshes (§9 tour, §10 BCC, §12 tables) -------------------
 
 def refresh_tours(fleet: ForestFleet, cached: TourNumbering | None = None,
-                  *, incremental: bool = True, use_kernel: bool = False,
-                  bucket: str | None = None):
+                  *, incremental: bool = True, use_kernel: bool = False):
     """Vmapped ``refresh_tour`` over the fleet.
 
     ``cached`` is the stacked numbering from the previous call (lane t
@@ -255,16 +252,14 @@ def refresh_tours(fleet: ForestFleet, cached: TourNumbering | None = None,
         tn, syncs = jax.vmap(lambda p, r, d, c: _merge_dirty(
             p, r, d, c, use_kernel=use_kernel, return_syncs=True))(
                 fleet.parent, fleet.rep, fleet.dirty, cached)
-    obs.record("fleet_refresh_tour", lambda: int(jnp.max(syncs)),
-               bucket=bucket)
+    obs.record("fleet_refresh_tour", lambda: int(jnp.max(syncs)))
     return tn, dataclasses.replace(
         fleet, dirty=jnp.zeros_like(fleet.dirty))
 
 
 def refresh_bccs(fleet: ForestFleet, cached: DynamicBCC | None = None, *,
                  tour: TourNumbering, incremental: bool = True,
-                 use_kernel: bool = False,
-                 bucket: str | None = None) -> DynamicBCC:
+                 use_kernel: bool = False) -> DynamicBCC:
     """Vmapped ``refresh_bcc`` over the fleet (stacked ``DynamicBCC``).
 
     Reports the refresh's sync bill (max over lanes of
@@ -279,13 +274,12 @@ def refresh_bccs(fleet: ForestFleet, cached: DynamicBCC | None = None, *,
         bcc = jax.vmap(lambda f, t, c: _refresh_incremental(
             f, t, c, use_kernel=use_kernel))(forest, tour, cached)
     obs.record("fleet_refresh_bcc",
-               lambda: int(jnp.max(bcc.seg_syncs + bcc.aux_rounds)),
-               bucket=bucket)
+               lambda: int(jnp.max(bcc.seg_syncs + bcc.aux_rounds)))
     return bcc
 
 
-def build_fleet_tables(tn: TourNumbering, *, n_jumps: int = DEFAULT_JUMPS,
-                       bucket: str | None = None) -> QueryTables:
+def build_fleet_tables(tn: TourNumbering, *,
+                       n_jumps: int = DEFAULT_JUMPS) -> QueryTables:
     """Vmapped §12 ``build_tables``: one stacked query index, built in
     one program (``build_syncs`` is per-tenant, int32[T]).
 
@@ -296,8 +290,7 @@ def build_fleet_tables(tn: TourNumbering, *, n_jumps: int = DEFAULT_JUMPS,
     from repro.core.queries import _build_tables
 
     tables = jax.vmap(lambda t: _build_tables(t, n_jumps=n_jumps))(tn)
-    obs.record("fleet_tables", lambda: int(jnp.max(tables.build_syncs)),
-               bucket=bucket)
+    obs.record("fleet_tables", lambda: int(jnp.max(tables.build_syncs)))
     return tables
 
 
@@ -871,7 +864,8 @@ class FleetBucket:
          an async ``prefetch`` instead of blocking the device;
       3. apply — up to ``max_drain`` dispatcher blocks (cross-tick
          carryover for bursty tenants), each one vmapped
-         ``apply_batches`` labeled with the bucket name;
+         ``apply_batches`` inside the bucket-labeled ``bucket_tick``
+         span;
       4. cadenced refresh — vmapped tour/BCC (+ optional query session)
          on the bucket's own ``CadencePolicy``. Any residency change
          since the last refresh forces a full (non-incremental) rebuild:
@@ -971,8 +965,7 @@ class FleetBucket:
             for block, served in self.dispatcher.drain(
                     mgr.tenant_at, max_blocks=self.max_drain):
                 mgr.fleet, stats = apply_batches(
-                    mgr.fleet, *block, use_kernel=self.use_kernel,
-                    bucket=self.name)
+                    mgr.fleet, *block, use_kernel=self.use_kernel)
                 mgr.note_applied(served)
                 self.sync_apply += fleet_sync_cost(stats)
                 self.blocks += 1
@@ -993,8 +986,8 @@ class FleetBucket:
         return dict(served_total)
 
     def refresh(self, step: int | None = None) -> None:
-        """Vmapped cache refresh for the whole bucket (bucket-labeled
-        ledger phases + span). Forced callers (end-of-run reporting)
+        """Vmapped cache refresh for the whole bucket (inside the
+        bucket-labeled ``fleet_refresh`` span). Forced callers (end-of-run reporting)
         call this directly, out of cadence."""
         mgr, cad = self.manager, self.cadence
         inc = not self._lanes_dirty
@@ -1004,15 +997,13 @@ class FleetBucket:
                 and inc
             self.tn, mgr.fleet = refresh_tours(
                 mgr.fleet, self.tn if inc_t else None,
-                incremental=inc_t, use_kernel=self.use_kernel,
-                bucket=self.name)
+                incremental=inc_t, use_kernel=self.use_kernel)
             if cad.bcc != "off":
                 inc_b = cad.bcc == "incremental" and self.bcc is not None \
                     and inc
                 self.bcc = refresh_bccs(
                     mgr.fleet, self.bcc if inc_b else None, tour=self.tn,
-                    incremental=inc_b, use_kernel=self.use_kernel,
-                    bucket=self.name)
+                    incremental=inc_b, use_kernel=self.use_kernel)
             if cad.queries:
                 if self.session is None:
                     self.session = FleetQuerySession.from_fleet(

@@ -24,6 +24,10 @@ stamp mismatch the ``policy`` decides:
                     (``stale_served``) — bounded-staleness serving for
                     read-heavy loops that refresh on a cadence.
 
+Each read op runs inside ``obs.span("query", op=<method name>)``: the
+span covers the staleness gate and the dispatch, not the device's
+answer, so it is the session's host cost per read batch.
+
 The session is a host-side mutable object (like
 ``launch.resilient.ResilientStreamLoop``), deliberately NOT a pytree:
 it owns amortization counters (``builds``, ``build_syncs_total``) that
@@ -32,10 +36,12 @@ it owns amortization counters (``builds``, ``build_syncs_total``) that
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import queries as q
 from repro.core.compress import DEFAULT_JUMPS
 from repro.core.euler import TourNumbering, tour_numbering
@@ -55,6 +61,17 @@ def _i32(x) -> jnp.ndarray:
 
 def _same(a: jnp.ndarray, b: jnp.ndarray) -> bool:
     return bool(np.array_equal(np.asarray(a), np.asarray(b)))
+
+
+def _read_op(method):
+    """Run a read op inside a ``query`` span named by its ``op``."""
+    op = method.__name__
+
+    @functools.wraps(method)
+    def traced(self, *args, **kwargs):
+        with obs.span("query", op=op):
+            return method(self, *args, **kwargs)
+    return traced
 
 
 @dataclasses.dataclass
@@ -162,27 +179,33 @@ class QuerySession:
 
     # -- tree queries (tour-interval + doubling tables) ----------------------
 
+    @_read_op
     def connected(self, state: DynamicForest, u, v) -> jnp.ndarray:
         self.ensure(state)
         return q.connected(self.tables, _i32(u), _i32(v))
 
+    @_read_op
     def depth(self, state: DynamicForest, v) -> jnp.ndarray:
         self.ensure(state)
         return q.depth_of(self.tables, _i32(v))
 
+    @_read_op
     def lca(self, state: DynamicForest, u, v) -> jnp.ndarray:
         self.ensure(state)
         return q.lca(self.tables, _i32(u), _i32(v))
 
+    @_read_op
     def is_ancestor(self, state: DynamicForest, a, x) -> jnp.ndarray:
         self.ensure(state)
         return q.is_ancestor(self.tables, _i32(a), _i32(x))
 
+    @_read_op
     def subtree_agg(self, state: DynamicForest, v, payload,
                     op: str = "add") -> jnp.ndarray:
         self.ensure(state)
         return q.subtree_agg(self.tables, _i32(v), jnp.asarray(payload), op)
 
+    @_read_op
     def path_agg(self, state: DynamicForest, u, v, payload,
                  op: str = "add") -> jnp.ndarray:
         self.ensure(state)
@@ -199,6 +222,7 @@ class QuerySession:
                 "is_bridge / is_articulation")
         return self.bcc
 
+    @_read_op
     def is_bridge(self, state: DynamicForest, u, v) -> jnp.ndarray:
         """bool[B] — some live (u, v) pool copy is a bridge.
 
@@ -215,6 +239,7 @@ class QuerySession:
             bcc.bridge[:cap])
         return flagged
 
+    @_read_op
     def is_articulation(self, state: DynamicForest, v) -> jnp.ndarray:
         self.ensure(state)
         bcc = self._require_bcc()

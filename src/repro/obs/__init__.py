@@ -1,15 +1,18 @@
 """Unified observability layer (DESIGN.md §14).
 
 Three jit-safe pieces, all host-side, all free when nothing is
-installed:
+installed (a span then costs one profiler annotation, about a
+microsecond):
 
   * ``SyncLedger`` (``ledger``) — the single sync-accounting path.
     Engine-loop host wrappers ``record(phase, syncs)`` the convergence
     counts the loops already return; benchmarks and reports read
     per-phase totals instead of re-deriving ad-hoc sums.
   * ``Tracer`` (``trace``) — span tracing of the serving loops with
-    per-span wall-clock AND sync attribution; exports JSONL and Chrome
-    trace-event JSON (Perfetto-loadable).
+    per-span wall-clock AND sync attribution, span ids and parents, and
+    the ledger's records as ``count`` records in their span; exports
+    JSONL and Chrome trace-event JSON (Perfetto-loadable). Every span is
+    also a ``jax.profiler.TraceAnnotation``, traced or not.
   * ``MetricsRegistry`` (``metrics``) — counters/gauges/histograms with
     per-tenant labels; ``percentile_line`` is the shared latency-report
     formatter (including the zero-sample path).

@@ -44,22 +44,27 @@ def recording() -> bool:
     return bool(_LEDGERS)
 
 
-def record(phase: str, syncs, *, tenant=None, bucket=None) -> None:
+def record(phase: str, syncs, *, tenant=None) -> None:
     """Report ``syncs`` convergence checks spent in ``phase``.
 
     No-op when no ledger is installed. ``syncs`` may be an int, a 0-d
     device scalar, or a zero-arg callable returning either — callables
     (and device→host pulls) are only evaluated while a ledger is
-    installed, so uninstrumented runs pay nothing. ``tenant`` and
-    ``bucket`` are optional attribution labels (stable tenant id /
-    sub-fleet bucket name, DESIGN.md §15); omitting them is the
-    PR-8-compatible default and changes nothing.
+    installed, so uninstrumented runs pay nothing. ``tenant`` is an
+    optional attribution label (a stable tenant id, DESIGN.md §15).
+    While a ``Tracer`` is installed the value is also appended to its
+    records as a ``count`` in the innermost open span.
     """
     if not _LEDGERS:
         return
     value = int(syncs() if isinstance(syncs, Callable) else syncs)
     for led in _LEDGERS:
-        led.add(phase, value, tenant=tenant, bucket=bucket)
+        led.add(phase, value, tenant=tenant)
+    from repro.obs.trace import current_tracer  # trace imports this module
+
+    tracer = current_tracer()
+    if tracer is not None:
+        tracer.count(phase, value)
 
 
 class SyncLedger:
@@ -74,22 +79,16 @@ class SyncLedger:
         self._totals: dict[str, int] = {}
         self._counts: dict[str, int] = {}
         self._tenant_totals: dict[tuple[str, object], int] = {}
-        self._bucket_totals: dict[tuple[str, object], int] = {}
 
     # -- recording -----------------------------------------------------------
 
-    def add(self, phase: str, syncs: int, *, tenant=None,
-            bucket=None) -> None:
+    def add(self, phase: str, syncs: int, *, tenant=None) -> None:
         self._totals[phase] = self._totals.get(phase, 0) + int(syncs)
         self._counts[phase] = self._counts.get(phase, 0) + 1
         if tenant is not None:
             key = (phase, tenant)
             self._tenant_totals[key] = \
                 self._tenant_totals.get(key, 0) + int(syncs)
-        if bucket is not None:
-            key = (phase, bucket)
-            self._bucket_totals[key] = \
-                self._bucket_totals.get(key, 0) + int(syncs)
 
     # -- reading -------------------------------------------------------------
 
@@ -112,16 +111,10 @@ class SyncLedger:
         return {t: v for (p, t), v in self._tenant_totals.items()
                 if p == phase}
 
-    def by_bucket(self, phase: str) -> dict:
-        """{bucket: syncs} for records that carried a bucket label."""
-        return {b: v for (p, b), v in self._bucket_totals.items()
-                if p == phase}
-
     def clear(self) -> None:
         self._totals.clear()
         self._counts.clear()
         self._tenant_totals.clear()
-        self._bucket_totals.clear()
 
     # -- install/uninstall ---------------------------------------------------
 

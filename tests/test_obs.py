@@ -1,22 +1,30 @@
 """Observability layer (DESIGN.md §14): sync ledger, span tracing,
-metrics registry — and the two contracts the whole subsystem stands on:
+metrics registry, span identity and counts, the spans' profiler
+annotations — and the two contracts the whole subsystem stands on:
 the ledger's totals are bit-equal to the engine's own ``return_syncs``
 counters (single sync-accounting path), and instrumentation is FREE —
 tracing on vs off leaves forest/tour/BCC state bit-identical and adds
 zero engine syncs, across all three stream generators, the fleet tick,
 and the full recovery ladder."""
+import glob
 import json
+import os
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from numpy.testing import assert_array_equal
 
 from repro import obs
+from repro.core import rooted_spanning_tree
 from repro.data import graphs as G
 from repro.data.streams import STREAMS
+from repro.dynamic.bcc import refresh_bcc
 from repro.dynamic.chaos import inject
 from repro.dynamic.fleet import (apply_batches, fleet_empty,
                                  fleet_sync_cost, refresh_tours)
+from repro.dynamic.queries import QuerySession
 from repro.dynamic.recovery import recover
 from repro.dynamic.replay import init_state, replay_batch
 from repro.dynamic.tour import refresh_tour
@@ -139,7 +147,7 @@ class TestTracer:
         path = tmp_path / "trace.chrome.json"
         tracer.write_chrome(path)
         chrome = json.loads(path.read_text())
-        assert {e["ph"] for e in chrome["traceEvents"]} == {"X", "i"}
+        assert {e["ph"] for e in chrome["traceEvents"]} == {"X", "C", "i"}
         assert chrome["otherData"]["sync_total"] == 5
         assert chrome["otherData"]["schema_version"] == obs.SCHEMA_VERSION
         assert obs.chrome_to_records(chrome) == tracer.records
@@ -147,6 +155,172 @@ class TestTracer:
     def test_no_tracer_span_is_noop(self):
         with obs.span("tick", step=0):
             obs.event("recovery", mode="full")  # must not raise
+
+    def test_span_ids_and_parents(self):
+        tracer = obs.Tracer()
+        with tracer:
+            with obs.span("tick", step=0):
+                with obs.span("apply_batch", step=0):
+                    with obs.span("inner"):
+                        pass
+                with obs.span("refresh_tour", step=0):
+                    pass
+            with obs.span("tick", step=1):
+                pass
+        by = {(s["name"], s["step"]): s for s in tracer.spans()}
+        tick0, tick1 = by[("tick", 0)], by[("tick", 1)]
+        apply_, refresh = by[("apply_batch", 0)], by[("refresh_tour", 0)]
+        inner = by[("inner", None)]
+        ids = [s["id"] for s in tracer.spans()]
+        assert len(set(ids)) == len(ids) == 5
+        assert tick0["parent"] is None and tick1["parent"] is None
+        assert apply_["parent"] == tick0["id"]
+        assert refresh["parent"] == tick0["id"]
+        assert inner["parent"] == apply_["id"]
+        # Spans are written as they close: a child before its parent.
+        order = [s["id"] for s in tracer.spans()]
+        assert order.index(inner["id"]) < order.index(apply_["id"]) \
+            < order.index(tick0["id"])
+
+    def test_counts_carry_their_parent_span(self):
+        tracer = self._traced()
+        tick, = tracer.spans("tick")
+        inner, = tracer.spans("apply_batch")
+        counts = tracer.counts("apply")
+        assert [(c["value"], c["parent"]) for c in counts] == \
+            [(3, tick["id"]), (2, inner["id"])]
+        assert all(c["v"] == obs.SCHEMA_VERSION for c in counts)
+        # The ledger's totals are the same as without counts.
+        assert tracer.ledger.totals() == {"apply": 5}
+        with tracer:
+            obs.record("audit", 1)           # outside every span
+        assert tracer.counts("audit")[0]["parent"] is None
+
+    def test_counts_lazy_and_only_under_a_tracer(self):
+        calls = []
+
+        def cost():
+            calls.append(1)
+            return 4
+
+        obs.record("apply", cost)            # nothing installed
+        assert calls == []
+        tracer = obs.Tracer()
+        with obs.SyncLedger() as led:        # a ledger alone: no count
+            obs.record("apply", cost)
+        assert calls == [1] and led.total("apply") == 4
+        assert tracer.counts() == []
+        with tracer:
+            obs.record("apply", cost)
+        assert calls == [1, 1]
+        count, = tracer.counts("apply")
+        assert count["value"] == 4 and count["parent"] is None
+
+    @pytest.mark.parametrize("fmt", ["jsonl", "chrome"])
+    def test_schema_2_round_trip(self, fmt, tmp_path):
+        tracer = self._traced()
+        assert obs.SCHEMA_VERSION == 2
+        kinds = {r["type"] for r in tracer.records}
+        assert kinds == {"span", "count", "event"}
+        summary = tracer.summary()
+        assert summary["v"] == 2 and isinstance(summary["origin_s"], float)
+        if fmt == "jsonl":
+            tracer.write_jsonl(tmp_path / "t.jsonl")
+            back = obs.read_jsonl(tmp_path / "t.jsonl")
+            assert back[:-1] == tracer.records and back[-1] == summary
+        else:
+            tracer.write_chrome(tmp_path / "t.json")
+            chrome = json.loads((tmp_path / "t.json").read_text())
+            assert obs.chrome_to_records(chrome) == tracer.records
+            assert chrome["otherData"]["origin_s"] == summary["origin_s"]
+            span = next(e for e in chrome["traceEvents"] if e["ph"] == "X")
+            assert {"id", "parent"} <= set(span["args"])
+
+
+# ---- spans on the profiler's clock --------------------------------------------
+
+def _host_events(logdir, names):
+    """(start, end, name) of the host-plane events named in ``names``."""
+    path, = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for p in pd.planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events if e.name in names]
+
+
+def test_untraced_step_spans_reach_the_profiler(tmp_path):
+    """With no Tracer installed, one loop step's spans show on the
+    profiler's host plane, nested in time."""
+    assert obs.current_tracer() is None
+    stream = _stream("churn", G.grid2d(8))
+    loop = ResilientStreamLoop.from_stream(
+        stream, tour_mode="incremental", bcc_mode="off", tour_every=1)
+    loop.step(0, stream.batches[0])             # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        loop.step(1, stream.batches[1])
+    finally:
+        jax.profiler.stop_trace()
+    got = _host_events(tmp_path, {"tick", "apply_batch", "refresh_tour"})
+    by = {}
+    for s, e, name in got:
+        by.setdefault(name, []).append((s, e))
+    assert {k: len(v) for k, v in by.items()} == \
+        {"tick": 1, "apply_batch": 1, "refresh_tour": 1}
+    (t0, t1), = by["tick"]
+    (a0, a1), = by["apply_batch"]
+    (r0, r1), = by["refresh_tour"]
+    assert t0 <= a0 < a1 <= r0 < r1 <= t1
+
+
+def test_each_query_op_runs_in_a_query_span():
+    stream = _stream("churn", G.grid2d(6), n=1)
+    state = init_state(stream)
+    tn, state = refresh_tour(state, None)
+    bcc = refresh_bcc(state, None, tour=tn)
+    sess = QuerySession.from_state(state, tn, bcc)
+    u = jnp.arange(4, dtype=jnp.int32)
+    v = u + 1
+    payload = jnp.ones(state.n_nodes, jnp.int32)
+    calls = [("connected", (u, v)), ("depth", (u,)), ("lca", (u, v)),
+             ("is_ancestor", (u, v)), ("subtree_agg", (u, payload)),
+             ("path_agg", (u, v, payload)), ("is_bridge", (u, v)),
+             ("is_articulation", (u,))]
+    tracer = obs.Tracer()
+    with tracer:
+        for op, args in calls:
+            getattr(sess, op)(state, *args)
+    spans = tracer.spans("query")
+    assert [s["args"]["op"] for s in spans] == [op for op, _ in calls]
+    assert all(s["parent"] is None and s["syncs"] == 0 for s in spans)
+
+
+@pytest.mark.parametrize("graph", ["grid", "rmat"])
+def test_gconn_rounds_counted_once_per_build(graph):
+    g = G.grid2d(9) if graph == "grid" else G.rmat(7, edge_factor=4, seed=3)
+    res = rooted_spanning_tree(g, 0, method="gconn_euler")  # compile
+    tracer = obs.Tracer()
+    with tracer:
+        res = rooted_spanning_tree(g, 3, method="gconn_euler")
+    count, = tracer.counts("gconn")
+    assert count["value"] == int(res.steps) > 0
+    assert tracer.ledger.totals() == {"gconn": int(res.steps)}
+
+
+def test_gconn_inside_a_jitted_caller_records_nothing():
+    """``bcc_batch`` traces ``gconn_euler_rst`` under jit and vmap: an
+    installed tracer must neither pull a traced value nor count it."""
+    from repro.core import bcc_batch
+
+    g = G.grid2d(3)
+    src, dst = jnp.stack([g.src, g.src]), jnp.stack([g.dst, g.dst])
+    tracer = obs.Tracer()
+    with tracer:
+        out = bcc_batch(src, dst, jnp.zeros(2, jnp.int32),
+                        n_nodes=g.n_nodes)
+    assert out.articulation.shape == (2, g.n_nodes)
+    assert tracer.counts("gconn") == []
 
 
 # ---- MetricsRegistry ---------------------------------------------------------
