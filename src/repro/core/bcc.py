@@ -16,7 +16,7 @@ GPU*, and Dong et al.'s low/high characterization):
   3. **Auxiliary graph** — one vertex per tree edge (identified with its
      child endpoint); two tree edges share a biconnected component iff
      connected under the three Tarjan–Vishkin rules (below). The final
-     components pass reuses GConn (``connectivity.connected_components``).
+     components pass reuses GConn's edge-list loop (``connectivity``).
   4. **Readout** — per-half-edge BCC labels (deeper endpoint's aux
      representative), bridges (subtree with no escaping non-tree edge),
      and articulation points (vertex incident to ≥ 2 distinct blocks).
@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.compress import segment_reduce, segment_reduce_scoped
-from repro.core.connectivity import connected_components
+from repro.core.connectivity import _components_of_edges
 from repro.core.euler import tour_numbering
 from repro.core.graph import Graph
 from repro.core.rst import METHODS, rooted_spanning_tree
@@ -226,8 +226,8 @@ def bcc_from_tour(graph: Graph, parent: jnp.ndarray, tn, *,
     aux_dst = jnp.concatenate([jnp.where(r1, dc, n),
                                jnp.where(r2, w, n),
                                jnp.where(r3, w, n)])
-    aux = Graph(n_nodes=n, src=aux_src, dst=aux_dst)
-    rep, _forest, aux_rounds = connected_components(aux,
+    # One entry per aux edge, not a paired ``Graph``: GConn reads it whole.
+    rep, _forest, aux_rounds = _components_of_edges(n, aux_src, aux_dst,
                                                     use_kernel=use_kernel)
 
     # Per-half-edge labels: every edge belongs to the block of the tree

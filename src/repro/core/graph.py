@@ -25,6 +25,12 @@ import numpy as np
 class Graph:
     """COO undirected graph as paired directed half-edges.
 
+    The pairing is a contract, not a convention: half-edges ``e`` and
+    ``e + M`` are the two directions of one undirected edge. GConn
+    (``connectivity.connected_components``) relies on it and reads only the
+    canonical half ``e < M``; an edge list that is not paired so is not a
+    ``Graph``.
+
     Attributes:
       n_nodes: static int, number of vertices.
       src, dst: int32[2M] directed half-edges; ``rev(e) = (e + M) % 2M``.

@@ -55,13 +55,15 @@ def gconn_euler_rst(graph: Graph, root, *, use_kernel: bool = False):
     rep, forest_mask, rounds = _connected_components(graph,
                                                      use_kernel=use_kernel)
 
-    # Compact marked half-edges into n-1 fixed slots.
+    # Compact marked half-edges into n-1 fixed slots. Winners are always
+    # canonical (e < M), so only the first half of the mask is searched.
+    m = graph.n_edges
     t = max(n - 1, 1)
-    slots = jnp.nonzero(forest_mask, size=t, fill_value=graph.src.shape[0])[0]
-    in_range = slots < graph.src.shape[0]
-    fu = jnp.where(in_range, graph.src[jnp.clip(slots, 0, graph.src.shape[0] - 1)], n)
-    fv = jnp.where(in_range, graph.dst[jnp.clip(slots, 0, graph.src.shape[0] - 1)], n)
-    valid = in_range
+    slots = jnp.nonzero(forest_mask[:m], size=t, fill_value=m)[0]
+    valid = slots < m
+    safe = jnp.clip(slots, 0, m - 1)
+    fu = jnp.where(valid, graph.src[safe], n)
+    fv = jnp.where(valid, graph.dst[safe], n)
 
     # Component containing ``root`` is rooted at ``root``; others at their rep.
     root = jnp.asarray(root, jnp.int32)

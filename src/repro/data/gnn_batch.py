@@ -148,6 +148,11 @@ def reorder_by_rst(graph_src: np.ndarray, graph_dst: np.ndarray,
                    n_nodes: int, method: str = "gconn_euler"):
     """Relabel nodes by RST order (paper technique in the data pipeline).
 
+    ``graph_src``/``graph_dst`` must be a paired half-edge list, as a
+    ``Graph``'s own arrays are: entry ``e + M`` reverses entry ``e``.
+    GConn reads only the first half, so an edge that appears only in the
+    second half is never hooked.
+
     Returns perm such that perm[old_id] = new_id; nodes contiguous within
     subtrees → better gather locality after sharding.
     """
